@@ -4,10 +4,12 @@ For every (mesh, aggregator, comm mode) combination this times the jitted
 shard_map'd aggregation step on synthetic worker gradients and emits
 ``BENCH_comm_modes.json`` plus a markdown table on stdout.
 
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
     PYTHONPATH=src python benchmarks/bench_comm_modes.py [--quick] \\
         [--coords N] [--reps R] [--out BENCH_comm_modes.json]
 
-On this CPU container the 8 forced host devices share one machine, so the
+The meshes need 8 devices.  On the CPU the 8 forced host devices (set by
+the caller, as above, never on a chip host) share one machine, so the
 numbers characterize compute + memory-movement volume, not TPU interconnect
 latency: ``gather`` runs the full-vector rule redundantly on every device
 (O(W * p) work and O(W * p_shard) bytes per device) while ``sharded`` runs
@@ -16,12 +18,6 @@ ordering between the modes is the scale-independent claim being validated.
 Per-device collective-byte estimates from that model are included in the
 JSON next to the measured wall-clock (schema: benchmarks/README.md).
 """
-import os
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-# The two lines above MUST run before jax is imported (jax locks the host
-# device count at first initialization); if XLA_FLAGS is already set it is
-# left alone, so CI / mesh_harness environments keep their own value.
-
 import argparse
 import functools
 import json
@@ -34,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 from repro import compat
 from repro.core import AGGREGATOR_NAMES, RobustConfig
 from repro.core.robust_step import distributed_aggregate, sharded_aggregate
+from repro.launch.compile_cache import enable_compile_cache
 
 SCHEMA = "BENCH_comm_modes/v1"
 
@@ -130,6 +127,7 @@ def main() -> None:
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--out", default="BENCH_comm_modes.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = QUICK_AGGREGATORS if args.quick else AGGREGATOR_NAMES
     rows = []

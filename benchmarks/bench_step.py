@@ -37,8 +37,9 @@ Paths:
   (``make_federated_step``) on a deep-MLP workload with MANY small
   parameter blocks -- the regime the packing targets (per-leaf dispatch
   multiplies kernel launches by num_leaves).
-* ``gather`` / ``sharded`` -- the distributed ``make_train_step`` on the
-  4x2 host mesh (8 forced devices), reduced mamba2 model.  The sharded
+* ``gather`` / ``sharded`` -- the distributed ``make_train_step`` on a
+  4 x (devices // 4) mesh of the devices present (4x2 on 8 forced host
+  devices, 4x1 on a four-chip host), reduced mamba2 model.  The sharded
   comm path re-shards by coordinate inside shard_map either way, so its
   packed/per-leaf cells differ only in the attack/packing stage.
 
@@ -48,19 +49,18 @@ per-leaf beyond a noise margin (on ``wall_us_min``, the noise-robust
 statistic), or if the sim geomed/krum cells -- the aggregation-dominated
 ones -- fall short of the 1.3x speedup floor.
 
-Process layout: the sim cells run IN-PROCESS on the natural device count
-(one CPU device -- forcing 8 host devices splits the XLA threadpool and
-drowns the sim numbers in scheduler noise on small containers), while the
-gather/sharded cells run in a SUBPROCESS with 8 forced host devices
-(``--distributed-only``), whose rows are merged into the report.
+Process layout: every cell runs in this one process, on the devices it
+finds (one process per chip); it never starts a second one.  The mesh is
+checked before the first cell.  On the CPU the sim cells time on the
+natural single device (forcing 8 host devices splits the XLA threadpool
+and drowns them in scheduler noise), so CI runs them with
+``--skip-distributed`` and the mesh cells in a second invocation,
+``--distributed-only --out BENCH_step_mesh.json`` under
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
 import argparse
 import json
 import math
-import os
-import subprocess
-import sys
-import tempfile
 import time
 
 import jax
@@ -71,6 +71,7 @@ from repro.core import AGGREGATOR_NAMES, RobustConfig, make_federated_step
 from repro.data import ijcnn1_like, partition
 from repro.launch import mesh as mesh_lib
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import get_optimizer
 
 SCHEMA = "BENCH_step/v5"
@@ -199,6 +200,7 @@ def bench_distributed(name: str, comm: str, packed: bool, steps: int,
     from repro.configs.base import TrainConfig
     from repro.launch.train import make_batch
     model, mesh, cfg_model = dist
+    w = mesh_lib.num_workers(mesh)
     robust = RobustConfig(aggregator=name, vr="sgd", attack="sign_flip",
                           num_byzantine=1, comm=comm, weiszfeld_iters=16,
                           num_groups=2, packed=packed)
@@ -208,14 +210,14 @@ def bench_distributed(name: str, comm: str, packed: bool, steps: int,
         params = model.init(jax.random.PRNGKey(0))
         state = {"params": params, "opt": (),
                  "step": jnp.zeros((), jnp.int32)}
-        batch = make_batch(jax.random.PRNGKey(5), cfg_model, 4, 1, 32)
+        batch = make_batch(jax.random.PRNGKey(5), cfg_model, w, 1, 32)
         jstep = steps_lib.compile_train_step(step_fn)
         t = time_steps(jstep, state, (batch, jax.random.PRNGKey(9)),
                        steps, reps)
     leaves = jax.tree_util.tree_leaves(model.param_structs())
     return {
         "path": comm, "aggregator": name, "packed": packed,
-        "num_workers": 4, "num_byzantine": 1, "vr": "sgd",
+        "num_workers": w, "num_byzantine": 1, "vr": "sgd",
         "vr_state_bytes": 0, "message_dtype": robust.message_dtype,
         "attack": "sign_flip", "leaves": len(leaves),
         "coords": sum(math.prod(s.shape) for s in leaves),
@@ -366,11 +368,10 @@ def run_gate(rows) -> list:
     return failures
 
 
-def distributed_rows(names, steps: int, reps: int) -> list:
+def distributed_rows(names, steps: int, reps: int, mesh) -> list:
     from repro.configs import get_config
     from repro.models.api import build_model
     cfg_model = get_config("mamba2-130m").reduced()
-    mesh = mesh_lib.make_host_mesh((4, 2), ("data", "model"))
     model = build_model(cfg_model, remat=False, q_chunk=32, kv_chunk=32,
                         loss_chunk=32)
     dist = (model, mesh, cfg_model)
@@ -386,26 +387,6 @@ def distributed_rows(names, steps: int, reps: int) -> list:
     return rows
 
 
-def spawn_distributed(args) -> list:
-    """Run the gather/sharded cells in a child process with 8 forced host
-    devices (the parent keeps its natural single device for the sim
-    cells), and merge its rows."""
-    out = tempfile.NamedTemporaryFile(suffix=".json", delete=False)
-    out.close()
-    cmd = [sys.executable, os.path.abspath(__file__), "--distributed-only",
-           "--steps", str(args.steps), "--reps", str(args.reps),
-           "--out", out.name]
-    if args.quick:
-        cmd.append("--quick")
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    subprocess.run(cmd, check=True, env=env)
-    with open(out.name) as f:
-        rows = json.load(f)["rows"]
-    os.unlink(out.name)
-    return rows
-
-
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
@@ -415,24 +396,22 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=30,
                     help="steps per timing rep (sim; distributed uses 1/5)")
     ap.add_argument("--reps", type=int, default=5)
-    ap.add_argument("--skip-distributed", action="store_true",
-                    help="simulation cells only (no 8-device mesh)")
-    ap.add_argument("--distributed-only", action="store_true",
-                    help="(internal) gather/sharded cells; needs >= 8 "
-                    "devices (XLA_FLAGS=--xla_force_host_platform_device_"
-                    "count=8)")
+    cells = ap.add_mutually_exclusive_group()
+    cells.add_argument("--skip-distributed", action="store_true",
+                       help="simulation cells only (no mesh)")
+    cells.add_argument("--distributed-only", action="store_true",
+                       help="gather/sharded cells only; needs >= 4 devices")
     ap.add_argument("--out", default="BENCH_step.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = QUICK_AGGREGATORS if args.quick else AGGREGATOR_NAMES
+    mesh = None
+    if not args.skip_distributed:
+        mesh = mesh_lib.make_host_mesh((4, max(jax.device_count() // 4, 1)),
+                                       ("data", "model"))
     rows = []
-    if args.distributed_only:
-        if jax.device_count() < 8:
-            raise SystemExit(
-                "--distributed-only needs 8 devices; set XLA_FLAGS="
-                "--xla_force_host_platform_device_count=8 before jax init")
-        rows += distributed_rows(names, args.steps, args.reps)
-    else:
+    if not args.distributed_only:
         data = ijcnn1_like(jax.random.PRNGKey(0), n=400)
         wd = partition({"a": data.x, "b": data.y}, SIM_HONEST, seed=1)
         for name in names:
@@ -469,8 +448,8 @@ def main() -> None:
         # Fault-containment cells (v5): fault x rule x guards.
         rows += bench_fault(wd, {"a": data.x, "b": data.y},
                             steps=GRID_STEPS if not args.quick else 100)
-        if not args.skip_distributed:
-            rows += spawn_distributed(args)
+    if mesh is not None:
+        rows += distributed_rows(names, args.steps, args.reps, mesh)
 
     report = {
         "schema": SCHEMA,
@@ -538,20 +517,19 @@ def main() -> None:
 
     if args.gate:
         failures = run_gate(rows)
-        if failures and not args.distributed_only:
+        if failures:
             # Up to two retry rounds for failing cells: on a loaded small
             # container a background burst during either side's timing
             # window can fake a regression; fresh measurements settle it
             # (min-across-runs -- scheduler interference only ever ADDS
             # time, so the min converges while a TRUE regression keeps
             # failing every round).  Sim cells re-time just the failing
-            # pairs in-process; a distributed failure re-spawns the
-            # 8-device subprocess (its cells are the noisiest -- eight
-            # forced host devices time-slice the real cores, so a single
-            # scheduler burst skews one side of a pair by 20%+).  The
-            # retried rows are folded back into the report and the JSON
-            # is re-dumped, so the uploaded artifact always matches the
-            # gate verdict.
+            # pairs; a distributed failure re-times the mesh cells (the
+            # noisiest on the CPU -- forced host devices time-slice the
+            # real cores, so a single scheduler burst skews one side of a
+            # pair by 20%+).  The retried rows are folded back into the
+            # report and the JSON is re-dumped, so the uploaded artifact
+            # always matches the gate verdict.
             retried = False
 
             def fold(fresh_rows):
@@ -585,7 +563,7 @@ def main() -> None:
                                     args.reps * 3, wd, vr=vr)
                           for packed in (False, True)])
                 if any(p in ("gather", "sharded") for p, _, _ in failing):
-                    fold(spawn_distributed(args))
+                    fold(distributed_rows(names, args.steps, args.reps, mesh))
                 failures = run_gate(rows)
                 if not failures:
                     break

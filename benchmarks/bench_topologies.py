@@ -46,6 +46,7 @@ import numpy as np
 from repro.core import AGGREGATOR_NAMES, RobustConfig, make_federated_step
 from repro.core.robust_step import resolve_schedule
 from repro.data import ijcnn1_like, logreg_loss, partition
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import get_optimizer
 
 SCHEMA = "BENCH_topologies/v2"
@@ -126,6 +127,7 @@ def main() -> None:
                     help="erdos_renyi topology seed")
     ap.add_argument("--out", default="BENCH_topologies.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     topologies = QUICK_TOPOLOGIES if args.quick else TOPOLOGIES
     aggregators = QUICK_AGGREGATORS if args.quick else AGGREGATOR_NAMES

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 
 # Wire element sizes the intensity report is parametrized over: the f32
 # baseline and the bf16 packed-message mode (DESIGN.md Sec. 8).
@@ -46,6 +47,7 @@ def _emit_intensity(name: str, us: float, flops: float,
 
 
 def main() -> None:
+    enable_compile_cache()
     key = jax.random.PRNGKey(0)
     w, p = 32, 65536
     z = jax.random.normal(key, (w, p))

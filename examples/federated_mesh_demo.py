@@ -1,26 +1,24 @@
 """Multi-device federation demo: the REAL distributed code path (shard_map /
-pjit) on 8 forced host devices — one mesh index per worker, model-parallel
-inner axis, geometric-median aggregation over the data axis, one Byzantine
-worker mounting a sign-flip attack.
+pjit) on a 4x2 mesh — one mesh index per worker, model-parallel inner axis,
+geometric-median aggregation over the data axis, one Byzantine worker
+mounting a sign-flip attack.  It needs 8 devices; on the CPU, force them
+from the caller's side:
 
-    PYTHONPATH=src python examples/federated_mesh_demo.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        PYTHONPATH=src python examples/federated_mesh_demo.py
 """
-import os
+import jax
+import jax.numpy as jnp
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from repro import compat  # noqa: E402
-from repro.configs import get_config  # noqa: E402
-from repro.configs.base import TrainConfig  # noqa: E402
-from repro.core.robust_step import RobustConfig  # noqa: E402
-from repro.launch import mesh as mesh_lib  # noqa: E402
-from repro.launch import steps as steps_lib  # noqa: E402
-from repro.launch.train import make_batch  # noqa: E402
-from repro.models.api import build_model  # noqa: E402
-from repro.optim import get_optimizer  # noqa: E402
+from repro import compat
+from repro.configs import get_config
+from repro.configs.base import TrainConfig
+from repro.core.robust_step import RobustConfig
+from repro.launch import mesh as mesh_lib
+from repro.launch import steps as steps_lib
+from repro.launch.train import make_batch
+from repro.models.api import build_model
+from repro.optim import get_optimizer
 
 
 def main() -> None:

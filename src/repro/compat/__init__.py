@@ -1,168 +1,87 @@
-"""Version-portability layer over jax's mesh / shard_map surface.
+"""The repo's one gate onto jax's mesh / shard_map / profiler surface.
 
 The distributed federation path (shard_map aggregation, ambient-mesh
-contexts, axis types) sits on APIs that moved between jax releases:
+contexts, axis types, multi-axis collectives) sits on jax names that have
+moved between releases.  The repo targets the one installed jax; call sites
+in core/, launch/, models/, examples/ and the tests import these wrappers
+and never touch the moving names directly (enforced by
+tests/test_substrates.py::test_compat_layer_is_the_only_jax_version_gate),
+so a future move changes this module only.  See DESIGN.md Sec. 3.
 
-================  ======================================  =========================
-canonical export  jax >= 0.6 surface                      jax 0.4.x fallback
-================  ======================================  =========================
-``shard_map``     ``jax.shard_map`` (axis_names=,         ``jax.experimental.shard_map
-                  check_vma=)                             .shard_map`` (auto=, check_rep=)
-``make_mesh``     ``jax.make_mesh(..., axis_types=)``     ``jax.make_mesh`` (no axis
-                                                          types) / ``jax.sharding.Mesh``
-``use_mesh``      ``jax.set_mesh`` / ``jax.sharding       thread-local mesh stack
-                  .use_mesh`` context                     (see :func:`active_mesh`)
-``AxisType``      ``jax.sharding.AxisType``               enum stub (Auto/Explicit/
-                                                          Manual)
-================  ======================================  =========================
-
-Every capability is probed with ``hasattr`` ONCE at import; call sites in
-core/, launch/, models/, examples/ and the test harness import from here and
-never touch the moving jax names directly (enforced by
-tests/test_substrates.py::test_compat_layer_is_the_only_jax_version_gate).
-See DESIGN.md Sec. 3 for the policy.
+================  =====================================================
+export            jax surface
+================  =====================================================
+``make_mesh``     ``jax.make_mesh(..., axis_types=, devices=)``
+``use_mesh``      ``jax.set_mesh`` context
+``active_mesh``   ``jax.sharding.get_abstract_mesh`` (None when unset)
+``shard_map``     ``jax.shard_map(axis_names=, check_vma=)``
+``AxisType``      ``jax.sharding.AxisType``
+================  =====================================================
 """
 from __future__ import annotations
 
 import contextlib
-import enum
-import inspect
-import threading
 from typing import Any, Callable, Optional, Sequence
 
 import jax
-import numpy as np
 
 __all__ = [
-    "JAX_VERSION", "AxisType", "HAS_AXIS_TYPE", "HAS_SHARD_MAP",
-    "HAS_AMBIENT_MESH", "make_mesh", "use_mesh", "active_mesh", "shard_map",
+    "AxisType", "make_mesh", "use_mesh", "active_mesh", "shard_map",
     "axis_size", "axis_group", "axis_index", "all_gather", "all_to_all",
     "psum", "pmax", "cost_analysis", "profiler_trace", "require_distributed",
 ]
 
-JAX_VERSION: tuple[int, ...] = tuple(
-    int(x) for x in jax.__version__.split(".")[:3] if x.isdigit())
-
-# ---------------------------------------------------------------------------
-# Capability probes -- run exactly once, at import.
-# ---------------------------------------------------------------------------
-
-_HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-_HAS_USE_MESH = hasattr(jax.sharding, "use_mesh")
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_HAS_MAKE_MESH = hasattr(jax, "make_mesh")
-_MAKE_MESH_KWARGS = (
-    frozenset(inspect.signature(jax.make_mesh).parameters)
-    if _HAS_MAKE_MESH else frozenset())
-
-_legacy_shard_map = None
-if not _HAS_TOPLEVEL_SHARD_MAP:
-    try:
-        from jax.experimental.shard_map import shard_map as _legacy_shard_map
-    except ImportError:  # pragma: no cover - ancient jax
-        _legacy_shard_map = None
-
-HAS_SHARD_MAP = _HAS_TOPLEVEL_SHARD_MAP or _legacy_shard_map is not None
-HAS_AMBIENT_MESH = _HAS_SET_MESH or _HAS_USE_MESH
+AxisType = jax.sharding.AxisType
 
 
-if HAS_AXIS_TYPE:
-    AxisType = jax.sharding.AxisType
-else:
-    class AxisType(enum.Enum):
-        """Stub of ``jax.sharding.AxisType`` for jax < 0.5: mesh axes are
-        implicitly Auto there, so the values only serve call-site symmetry."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+def _shortfall(what: str, need: int, devs: Sequence[Any]) -> str:
+    """Too-few-devices message: chips on an accelerator; on the CPU backend
+    it names the host-device flag that rehearses a mesh without one."""
+    platform = devs[0].platform if devs else jax.default_backend()
+    if platform != "cpu":
+        return f"{what} needs {need} chips, found {len(devs)}"
+    return (f"{what} needs {need} devices, found {len(devs)} -- on CPU set "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count={need} "
+            "before importing jax")
 
 
 # ---------------------------------------------------------------------------
-# Mesh construction
+# Mesh construction and the ambient mesh
 # ---------------------------------------------------------------------------
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence[Any]] = None,
               axis_types: Optional[Sequence[Any]] = None):
-    """Version-stable ``jax.make_mesh``.
-
-    Slices ``devices`` (default: all) to the mesh size with a clear error
-    when there are too few; passes ``axis_types`` (default: Auto everywhere)
-    only where the running jax understands it.
-    """
+    """``jax.make_mesh`` over the first prod(axis_shapes) of ``devices``
+    (default: all), with a clear error when there are too few; axis types
+    default to Auto everywhere."""
     shape = tuple(axis_shapes)
-    names = tuple(axis_names)
     n = 1
     for s in shape:
         n *= s
     devs = list(jax.devices() if devices is None else devices)
     if len(devs) < n:
-        raise RuntimeError(
-            f"mesh {shape} needs {n} devices, found {len(devs)} -- set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
-            "importing jax (launch/dryrun.py does this)")
-    devs = devs[:n]
-    if _HAS_MAKE_MESH:
-        kwargs: dict[str, Any] = {"devices": devs}
-        if "axis_types" in _MAKE_MESH_KWARGS:
-            kwargs["axis_types"] = (tuple(axis_types) if axis_types is not None
-                                    else (AxisType.Auto,) * len(shape))
-        return jax.make_mesh(shape, names, **kwargs)
-    return jax.sharding.Mesh(np.asarray(devs).reshape(shape), names)
-
-
-# ---------------------------------------------------------------------------
-# Ambient mesh context
-# ---------------------------------------------------------------------------
-
-_tls = threading.local()
+        raise RuntimeError(_shortfall(f"mesh {shape}", n, devs))
+    types = (tuple(axis_types) if axis_types is not None
+             else (AxisType.Auto,) * len(shape))
+    return jax.make_mesh(shape, tuple(axis_names), axis_types=types,
+                         devices=devs[:n])
 
 
 def active_mesh():
-    """The innermost mesh entered via :func:`use_mesh` (this thread), or the
-    jax-native ambient mesh where one exists, else None."""
-    stack = getattr(_tls, "mesh_stack", None)
-    if stack:
-        return stack[-1]
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        mesh = get_abstract()
-        if mesh is not None and getattr(mesh, "axis_names", ()):
-            return mesh
-    return None
+    """The mesh entered via :func:`use_mesh` (as jax's abstract mesh, which
+    is also readable under ``jit``), else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.axis_names else None
 
 
 @contextlib.contextmanager
 def use_mesh(mesh):
-    """``with use_mesh(mesh):`` -- the version-stable spelling of
-    ``with jax.set_mesh(mesh):``.
-
-    On jax with ambient-mesh support the native context is entered too, so
-    bare-PartitionSpec APIs keep working; on jax 0.4.x the mesh is tracked in
-    a thread-local stack that :func:`shard_map` and :func:`active_mesh`
-    resolve against (all repo call sites pass explicit NamedShardings, so
-    nothing else needs the ambient mesh there).
-    """
-    stack = getattr(_tls, "mesh_stack", None)
-    if stack is None:
-        stack = _tls.mesh_stack = []
-    stack.append(mesh)
-    try:
-        if _HAS_SET_MESH:
-            cm = jax.set_mesh(mesh)
-            if hasattr(cm, "__enter__"):
-                with cm:
-                    yield mesh
-            else:  # pragma: no cover - set_mesh variants that only set globally
-                yield mesh
-        elif _HAS_USE_MESH:
-            with jax.sharding.use_mesh(mesh):
-                yield mesh
-        else:
-            yield mesh
-    finally:
-        stack.pop()
+    """``with use_mesh(mesh):`` -- enter ``mesh`` as the ambient mesh
+    (``jax.set_mesh``), so bare-PartitionSpec APIs and :func:`shard_map`
+    without ``mesh=`` resolve against it."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 # ---------------------------------------------------------------------------
@@ -171,12 +90,10 @@ def use_mesh(mesh):
 
 def shard_map(f: Callable, *, mesh=None, in_specs, out_specs,
               axis_names: Optional[Any] = None, check_vma: bool = True):
-    """Version-stable ``jax.shard_map``.
+    """``jax.shard_map``.
 
     ``mesh``: defaults to :func:`active_mesh` (enter :func:`use_mesh` first).
-    ``axis_names``: the MANUAL mesh axes (new-jax convention); None means all
-    axes.  On jax 0.4.x this is translated to the complementary ``auto=`` set
-    and ``check_vma`` to ``check_rep``.
+    ``axis_names``: the MANUAL mesh axes; None means all axes.
     """
     if mesh is None:
         mesh = active_mesh()
@@ -190,59 +107,27 @@ def shard_map(f: Callable, *, mesh=None, in_specs, out_specs,
     if unknown:
         raise ValueError(f"axis_names {sorted(unknown)} not in mesh axes "
                          f"{sorted(all_names)}")
-    if _HAS_TOPLEVEL_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=set(manual),
-                             check_vma=check_vma)
-    if _legacy_shard_map is None:
-        raise RuntimeError(_NO_SHARD_MAP_MSG)
-    return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=bool(check_vma),
-                             auto=frozenset(all_names - manual))
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=set(manual),
+                         check_vma=check_vma)
 
 
-@contextlib.contextmanager
 def profiler_trace(log_dir: str):
-    """``with profiler_trace(dir):`` -- version-stable ``jax.profiler.trace``.
-
-    The context-manager spelling exists on every jax this repo supports, but
-    guard it anyway (some stripped builds ship only start_trace/stop_trace)
-    so the telemetry layer (``launch/train.py --profile-steps``) degrades to
-    the explicit pair instead of crashing mid-run.  Remember to
+    """``with profiler_trace(dir):`` -- ``jax.profiler.trace``.  Remember to
     ``block_until_ready`` inside the window: dispatch returns early, and an
-    empty trace is the classic symptom.
-    """
-    prof = jax.profiler
-    if hasattr(prof, "trace"):
-        with prof.trace(log_dir):
-            yield
-        return
-    prof.start_trace(log_dir)  # pragma: no cover - stripped-profiler builds
-    try:
-        yield
-    finally:
-        prof.stop_trace()
+    empty trace is the classic symptom."""
+    return jax.profiler.trace(log_dir)
 
 
 def cost_analysis(compiled) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jax versions: 0.4.x
-    returns a one-element list of dicts (per executable), newer jax the dict
-    itself."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
-_HAS_LAX_AXIS_SIZE = hasattr(jax.lax, "axis_size")
+    """``Compiled.cost_analysis()`` as a dict (empty when the backend
+    reports none)."""
+    return compiled.cost_analysis() or {}
 
 
 def axis_size(axis_name):
-    """``jax.lax.axis_size`` (jax >= 0.6); on older jax the size is recovered
-    as ``psum(1, axis)``, which the tracer folds to a static int."""
-    if _HAS_LAX_AXIS_SIZE:
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """``jax.lax.axis_size``: the static size of a mesh axis."""
+    return jax.lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------
@@ -251,11 +136,9 @@ def axis_size(axis_name):
 # The worker dimension of the Byzantine-robust federation may span SEVERAL
 # mesh axes (("pod", "data") on multi-pod meshes, launch/mesh.py).  jax's
 # collectives accept a tuple of axis names and treat it as one collapsed
-# axis whose index is row-major over the tuple (pod-major): verified on
-# jax 0.4.37 and the current releases for all_gather / all_to_all /
-# axis_index inside fully-manual shard_map.  These wrappers are the single
-# guard point for that surface -- if a future jax moves the multi-axis
-# collective API (as shard_map/make_mesh did), only this module changes.
+# axis whose index is row-major over the tuple (pod-major) for
+# all_gather / all_to_all / axis_index inside fully-manual shard_map.
+# These wrappers are the single guard point for that surface.
 # ---------------------------------------------------------------------------
 
 def axis_group(axis_names):
@@ -270,8 +153,8 @@ def axis_group(axis_names):
 
 def axis_index(axis_names):
     """Linear index along (possibly several) mesh axes, row-major.  Only use
-    inside FULLY-manual shard_map: partial-manual shard_map on jax 0.4.x
-    cannot lower axis_index (DESIGN.md Sec. 2 -- use a sharded iota there)."""
+    inside FULLY-manual shard_map (DESIGN.md Sec. 2 -- partial-manual
+    regions use a sharded iota instead)."""
     return jax.lax.axis_index(axis_group(axis_names))
 
 
@@ -300,25 +183,10 @@ def pmax(x, axis_names):
     return jax.lax.pmax(x, axis_group(axis_names))
 
 
-_NO_SHARD_MAP_MSG = (
-    f"jax {jax.__version__} provides neither jax.shard_map nor "
-    "jax.experimental.shard_map.shard_map; the distributed federation path "
-    "cannot run.  Upgrade jax (tested: 0.4.37 and >= 0.6) or use the "
-    "single-host simulation (repro.core.robust_step.make_federated_step).")
-
-
 def require_distributed(*, min_devices: int = 0, what: str = "distributed path") -> None:
-    """Capability probe for the multi-device federation path.
-
-    Raises a RuntimeError up front -- with the version/flag fix spelled out --
-    instead of letting an AttributeError (missing shard_map) or a mesh-size
-    error surface from deep inside jit tracing.
-    """
-    if not HAS_SHARD_MAP:
-        raise RuntimeError(f"{what}: {_NO_SHARD_MAP_MSG}")
-    if min_devices and len(jax.devices()) < min_devices:
-        raise RuntimeError(
-            f"{what} needs >= {min_devices} devices, found "
-            f"{len(jax.devices())} -- on CPU set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={min_devices} "
-            "before importing jax")
+    """Fail up front, with the fix spelled out, when the multi-device
+    federation path lacks devices -- instead of a mesh-size error from deep
+    inside jit tracing."""
+    devs = jax.devices()
+    if min_devices and len(devs) < min_devices:
+        raise RuntimeError(_shortfall(what, min_devices, devs))

@@ -1,9 +1,12 @@
 """Public jit'd wrappers over the Pallas kernels (padding, full geomed loop).
 
-On this CPU container the kernels execute with ``interpret=True`` (the
-kernel bodies run in Python/XLA-CPU, numerically identical); on a TPU
-runtime ``interpret=False`` compiles them to Mosaic.  ``INTERPRET`` is
-resolved from the backend at import time and can be overridden per call.
+On a TPU the kernels compile to Mosaic.  On the CPU backend they run with
+``interpret=True`` (the kernel bodies run as XLA-CPU ops, numerically
+identical) -- a correctness harness, not a fast path.  The mode is resolved
+when a wrapper is traced, from the default backend (:func:`_interpret`);
+importing this module touches no backend.  Any other backend compiles the
+kernel as for the chip and fails loudly if it cannot: nothing falls back
+to interpret mode or to a jnp reference in silence.
 """
 from __future__ import annotations
 
@@ -19,8 +22,14 @@ from repro.kernels import saga_correct as sc
 from repro.kernels import topology as tp
 from repro.kernels import weiszfeld as wz
 
-INTERPRET = jax.default_backend() == "cpu"
 _TILE = wz.DEFAULT_TILE
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    """An explicit ``interpret`` wins; else interpret only on the CPU."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"
 
 
 def _pad_p(x: jnp.ndarray, tile: int, axis: int = -1):
@@ -37,7 +46,7 @@ def _pad_p(x: jnp.ndarray, tile: int, axis: int = -1):
 def weiszfeld_step(z: jnp.ndarray, y: jnp.ndarray, *, tile: int = _TILE,
                    interpret: Optional[bool] = None) -> jnp.ndarray:
     """One fused Weiszfeld iteration on (W, p) messages."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     zp, p = _pad_p(z, tile)
     yp, _ = _pad_p(y, tile)
     sq = wz.partial_sqdist_call(zp, yp, tile=tile, interpret=interp)
@@ -69,7 +78,7 @@ def partial_sqdist_segments(z: jnp.ndarray, y: jnp.ndarray,
     instead of num_segments sqdist passes -- the TPU form of the segment sum
     in ``core/geomed.weiszfeld_blockwise_sharded`` (not yet wired into that
     shard_map path); padding introduced here contributes to no block."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     zp, p = _pad_p(z, tile)
     yp, _ = _pad_p(y, tile)
     onehot = (seg_ids[None, :] == jnp.arange(num_segments)[:, None]).astype(
@@ -84,7 +93,7 @@ def saga_correct(grad: jnp.ndarray, table: jnp.ndarray, avg: jnp.ndarray,
                  idx: jnp.ndarray, *, tile: int = _TILE,
                  interpret: Optional[bool] = None):
     """Fused SAGA correct+update on a raveled (p,) gradient."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     gp, p = _pad_p(grad, tile)
     tp, _ = _pad_p(table, tile)
     ap, _ = _pad_p(avg, tile)
@@ -100,7 +109,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     interpret: Optional[bool] = None) -> jnp.ndarray:
     """Flash attention on (B, S, H, hd) tensors with GQA (KV <= H heads,
     repeated on entry).  Output dtype follows q."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     b, s, h, hd = q.shape
     kv = k.shape[2]
     if kv != h:
@@ -124,7 +133,7 @@ def masked_neighbor_reduce(exchange: jnp.ndarray, mask: jnp.ndarray, *,
     ``topology/masked.py`` is the oracle-checked reference, this is the
     TPU form (one HBM sweep, no sort).  Padding coordinates introduced
     here average masked zeros and are stripped before returning."""
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     ep, d = _pad_p(exchange, tile)
     return tp.masked_neighbor_reduce_call(ep, mask, trim=trim, tile=tile,
                                           interpret=interp)[:, :d]
@@ -133,7 +142,7 @@ def masked_neighbor_reduce(exchange: jnp.ndarray, mask: jnp.ndarray, *,
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def coordinate_median(z: jnp.ndarray, *, tile: int = _TILE,
                       interpret: Optional[bool] = None) -> jnp.ndarray:
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     zp, p = _pad_p(z, tile)
     return rs.coordinate_median_call(zp, tile=tile, interpret=interp)[:p]
 
@@ -141,6 +150,6 @@ def coordinate_median(z: jnp.ndarray, *, tile: int = _TILE,
 @functools.partial(jax.jit, static_argnames=("trim", "tile", "interpret"))
 def trimmed_mean(z: jnp.ndarray, *, trim: int = 1, tile: int = _TILE,
                  interpret: Optional[bool] = None) -> jnp.ndarray:
-    interp = INTERPRET if interpret is None else interpret
+    interp = _interpret(interpret)
     zp, p = _pad_p(z, tile)
     return rs.trimmed_mean_call(zp, trim, tile=tile, interpret=interp)[:p]

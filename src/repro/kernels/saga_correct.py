@@ -19,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TILE = 512
 
@@ -27,7 +28,7 @@ def _saga_kernel(idx_ref, grad_ref, table_ref, avg_ref,
                  msg_ref, avg_out_ref, table_out_ref, *, num_samples: int):
     idx = idx_ref[0]
     g = grad_ref[...].astype(jnp.float32)             # (1, T)
-    old = pl.load(table_ref, (pl.dslice(idx, 1), slice(None))).astype(jnp.float32)
+    old = table_ref[pl.ds(idx, 1), :].astype(jnp.float32)
     avg = avg_ref[...].astype(jnp.float32)
     delta = g - old
     msg_ref[...] = (delta + avg).astype(msg_ref.dtype)
@@ -35,8 +36,7 @@ def _saga_kernel(idx_ref, grad_ref, table_ref, avg_ref,
     # Copy-through + row update (aliased, so only the dirty row really moves
     # on TPU; interpret mode materializes the copy which is fine for tests).
     table_out_ref[...] = table_ref[...]
-    pl.store(table_out_ref, (pl.dslice(idx, 1), slice(None)),
-             g.astype(table_out_ref.dtype))
+    table_out_ref[pl.ds(idx, 1), :] = g.astype(table_out_ref.dtype)
 
 
 def saga_correct_call(grad: jnp.ndarray, table: jnp.ndarray, avg: jnp.ndarray,
@@ -53,7 +53,7 @@ def saga_correct_call(grad: jnp.ndarray, table: jnp.ndarray, avg: jnp.ndarray,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (0,)),            # idx (scalar in vector)
+            pl.BlockSpec(memory_space=pltpu.SMEM),         # idx (scalar)
             pl.BlockSpec((1, tile), lambda i: (0, i)),     # grad
             pl.BlockSpec((j, tile), lambda i: (0, i)),     # table
             pl.BlockSpec((1, tile), lambda i: (0, i)),     # avg

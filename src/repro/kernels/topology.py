@@ -11,8 +11,8 @@ sender axis resident on-chip and fuses the masking, trimming, and reduction
 into ONE HBM sweep.
 
 * :func:`masked_neighbor_reduce_call` -- grid over (receiver, d-tile); each
-  grid step loads one receiver's (S, T) slab + its (S,) mask row and emits
-  the (T,) masked (trimmed) mean.
+  grid step loads one receiver's (S, T) slab + its mask row as an (S, 1)
+  column and emits the (T,) masked (trimmed) mean.
 
 Trimming avoids sorting (TPU-hostile): ``trim`` rounds of extreme
 elimination, each removing exactly ONE occurrence of the current masked
@@ -37,9 +37,9 @@ DEFAULT_TILE = 512
 
 def _masked_reduce_kernel(e_ref, m_ref, out_ref, *, trim: int):
     z = e_ref[0].astype(jnp.float32)             # (S, T)
-    m = m_ref[...].astype(jnp.float32)           # (1, S)
+    m = m_ref[0].astype(jnp.float32)             # (S, 1)
     s = z.shape[0]
-    valid = jnp.broadcast_to(m.reshape(s, 1) > 0, z.shape)
+    valid = jnp.broadcast_to(m > 0, z.shape)
     n = jnp.sum(m)
 
     row_ids = jax.lax.broadcasted_iota(jnp.int32, z.shape, 0)
@@ -60,8 +60,8 @@ def _masked_reduce_kernel(e_ref, m_ref, out_ref, *, trim: int):
         first = jnp.min(jnp.where(hit, row_ids, s), axis=0, keepdims=True)
         work = work & (row_ids != first)
 
-    total = jnp.sum(jnp.where(work, z, 0.0), axis=0)
-    out_ref[...] = (total / jnp.maximum(n - 2 * trim, 1.0)).reshape(1, -1)
+    total = jnp.sum(jnp.where(work, z, 0.0), axis=0, keepdims=True)
+    out_ref[0] = total / jnp.maximum(n - 2 * trim, 1.0)
 
 
 def masked_neighbor_reduce_call(exchange: jnp.ndarray, mask: jnp.ndarray, *,
@@ -75,14 +75,18 @@ def masked_neighbor_reduce_call(exchange: jnp.ndarray, mask: jnp.ndarray, *,
     assert mask.shape == (r, s), (mask.shape, (r, s))
     assert d % tile == 0, (d, tile)
     grid = (r, d // tile)
-    return pl.pallas_call(
+    # Mask and output carry a unit axis so each block's last two dims are
+    # either (8, 128)-aligned or the array's own -- the Mosaic block rule:
+    # the mask row arrives as an (S, 1) column, the output as a (1, T) row.
+    out = pl.pallas_call(
         functools.partial(_masked_reduce_kernel, trim=trim),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, s, tile), lambda i, k: (i, 0, k)),
-            pl.BlockSpec((1, s), lambda i, k: (i, 0)),
+            pl.BlockSpec((1, s, 1), lambda i, k: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile), lambda i, k: (i, k)),
-        out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, tile), lambda i, k: (i, 0, k)),
+        out_shape=jax.ShapeDtypeStruct((r, 1, d), jnp.float32),
         interpret=interpret,
-    )(exchange, mask)
+    )(exchange, mask.reshape(r, s, 1))
+    return out.reshape(r, d)

@@ -13,8 +13,8 @@ is the row-major linear index over the worker axes (pod-major) -- the order
 every collective in ``core/robust_step.py`` collapses those axes to
 (``repro.compat.all_gather`` / ``all_to_all`` / ``axis_index``).
 
-All mesh construction funnels through ``repro.compat.make_mesh`` so the same
-code runs on jax 0.4.x (no axis_types) and >= 0.6 (explicit AxisType.Auto).
+All mesh construction funnels through ``repro.compat.make_mesh`` (explicit
+AxisType.Auto axes, a clear error when the mesh needs more devices).
 """
 from __future__ import annotations
 

@@ -1,16 +1,22 @@
 """Runnable distributed training driver.
 
-CPU-scale entry point for the same code path the dry-run lowers: builds a
-host mesh over however many local devices exist, initializes real params,
-and runs Byzantine-robust data-parallel training on synthetic LM data.
+Entry point for the same code path the dry-run lowers: builds a mesh over
+the local devices (chips, or forced host devices on the CPU), initializes
+real params, and runs Byzantine-robust data-parallel training on synthetic
+LM data.  On a four-chip host:
+
+    PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m \\
+        --steps 20 --mesh 4x1 --aggregator geomed --attack sign_flip --byzantine 1
+
+A CPU rehearsal of a mesh forces host devices from the caller's side
+(never on a chip host):
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
     PYTHONPATH=src python -m repro.launch.train --arch mamba2-130m --reduced \\
         --steps 20 --mesh 4x2 --aggregator geomed --attack sign_flip --byzantine 1
 
-(The flag must be set by the caller; unlike dryrun.py this driver is meant
-to also run on real multi-chip platforms where forcing a device count would
-be wrong.)
+``main(argv)`` is also callable in-process and returns the last flushed
+metric row.
 """
 from __future__ import annotations
 
@@ -18,6 +24,7 @@ import argparse
 import json
 import os
 import time
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -35,6 +42,7 @@ from repro.core import guards as guards_lib
 from repro.launch import health as health_lib
 from repro.launch import hlo_analysis
 from repro.launch import mesh as mesh_lib
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch import sharding as shard_lib
 from repro.launch import steps as steps_lib
 from repro.models.api import build_model
@@ -57,7 +65,9 @@ def make_batch(key, cfg, num_workers: int, per_worker: int, seq: int):
     return batch
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> dict:
+    """Run the CLI on ``argv`` (default: ``sys.argv[1:]``) and return the
+    last flushed metric row (empty when no step ran)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -180,12 +190,13 @@ def main() -> None:
     ap.add_argument("--profile-steps", type=int, default=0,
                     help="capture a profiler trace of this many post-warmup "
                     "steps into <log-dir>/profile (needs --log-dir)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.resume and not args.checkpoint_dir:
         raise SystemExit("--resume needs --checkpoint-dir")
     if args.profile_steps and not args.log_dir:
         raise SystemExit("--profile-steps needs --log-dir")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -230,15 +241,16 @@ def main() -> None:
     def build_step(rcfg):
         """Step builder keyed on the (possibly ladder-escalated) robust
         config; the state STRUCTURE must not change across rebuilds
-        (launch/health.py forbids structure-changing ladder fields)."""
+        (launch/health.py forbids structure-changing ladder fields).
+        Returns the jitted step and the state's shardings."""
         if decentralized:
-            fn, _, _ = steps_lib.make_decentralized_train_step(
+            fn, specs, _ = steps_lib.make_decentralized_train_step(
                 model, rcfg, train, mesh, sched,
                 saga_num_samples=saga_samples)
         else:
-            fn, _, _ = steps_lib.make_train_step(
+            fn, specs, _ = steps_lib.make_train_step(
                 model, rcfg, train, mesh, saga_num_samples=saga_samples)
-        return steps_lib.compile_train_step(fn)
+        return steps_lib.compile_train_step(fn), shard_lib.named(mesh, specs)
 
     if decentralized:
         # Schedule-level report: per-round spectral gaps + the joint gap.
@@ -286,7 +298,12 @@ def main() -> None:
                 print(f"resumed full train state from step {step0}")
         # State donation lives in the step compiler (launch/steps.py):
         # params, opt moments and the VR state are all in arg 0.
-        jstep = build_step(robust)
+        jstep, state_shardings = build_step(robust)
+        # Lay the state out as the step's specs say (node axis and
+        # per-client tables over the worker axes): created under the mesh it
+        # is a full replica on every device, which costs each chip the whole
+        # federation's state and the first step a compile of its own.
+        state = jax.device_put(state, state_shardings)
         log_dir = args.log_dir or None
         t0 = time.time()
 
@@ -386,6 +403,7 @@ def main() -> None:
                 # (tests/test_rollback.py).
                 logger.flush()
                 gstep, state = ckpt.restore_last_good(state)
+                state = jax.device_put(state, state_shardings)
                 monitor.on_rollback()
                 if gstep is None:
                     print("run unhealthy but no restorable checkpoint; "
@@ -394,7 +412,7 @@ def main() -> None:
                     escalated = monitor.escalate(robust)
                     if escalated != robust:
                         robust = escalated
-                        jstep = build_step(robust)
+                        jstep, _ = build_step(robust)
                         print(f"rollback #{monitor.rollbacks}: restored "
                               f"step {gstep}, escalated to "
                               f"aggregator={robust.aggregator} "
@@ -434,6 +452,7 @@ def main() -> None:
                 json.dump(meta, f, indent=2, sort_keys=True, default=str)
                 f.write("\n")
     print(f"done ({args.steps - start} steps, {time.time() - t0:.1f}s)")
+    return last_row
 
 
 if __name__ == "__main__":

@@ -246,17 +246,15 @@ def decode_attention(params, x, cache, pos, *, num_heads: int,
         # shard_map that is manual over the seq axis only ('model' and batch
         # sharding stay under the automatic partitioner).  The mesh comes from
         # the ambient compat.use_mesh context.  Each shard learns its own
-        # index from a P(ax)-sharded iota instead of lax.axis_index, which
-        # old-jax partial-manual shard_map cannot lower (PartitionId op).
+        # index from a P(ax)-sharded iota instead of lax.axis_index
+        # (DESIGN.md Sec. 2: partial-manual regions avoid axis_index).
         ax = seq_shard_axis
         mesh = compat.active_mesh()
         if mesh is None:
             raise RuntimeError(
                 "sequence-sharded decode needs an ambient mesh -- wrap the "
                 "call in `with repro.compat.use_mesh(mesh):`")
-        n_shards = dict(zip(mesh.axis_names, mesh.axis_sizes
-                            if hasattr(mesh, "axis_sizes")
-                            else tuple(mesh.shape.values())))[ax]
+        n_shards = dict(zip(mesh.axis_names, mesh.axis_sizes))[ax]
         shard_ids = jnp.arange(n_shards, dtype=jnp.int32)
         kv_spec = P(None, ax, None, None)
         fn = functools.partial(_sharded_cache_attn, axis=ax, window=window)

@@ -102,7 +102,10 @@ def _ssd_chunked(x, b_in, c_in, dt, a, *, chunk: int,
     # Intra-chunk (masked attention-like) term.
     rel = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # (B,nc,Qt,Qi,H) = cum_t - cum_i
     mask = jnp.tril(jnp.ones((q, q), bool))
-    gate = jnp.where(mask[None, None, :, :, None], jnp.exp(rel), 0.0)
+    # Mask BEFORE the exp: above the diagonal rel > 0 grows with the chunk
+    # and overflows to inf (about 88 nats of decay in f32), and the
+    # gradient of where(mask, inf, 0) is 0 * inf = NaN.
+    gate = jnp.exp(jnp.where(mask[None, None, :, :, None], rel, -jnp.inf))
     scores = jnp.einsum("bcqn,bcin->bcqi", cs.astype(jnp.float32), bs.astype(jnp.float32))
     scores = scores[..., None] * gate * dts[:, :, None, :, :]  # (B,nc,Qt,Qi,H)
     y_intra = jnp.einsum("bcqih,bcihp->bcqhp", scores, xs.astype(jnp.float32))

@@ -810,9 +810,21 @@ def decentralized_aggregate(
     # robust_step.sharded_aggregate, but aggregate ALL receivers' masked
     # neighborhoods on this device's slice and route each receiver its own
     # result with a second all_to_all (DESIGN.md Sec. 6).
-    flat, unflatten, leaf_sizes = _flatten_concat(grads)
+    flat, _, leaf_sizes = _flatten_concat(grads)
     p = flat.shape[0]
     pad = (-p) % w
+
+    def unflatten(vec):
+        # Slice the f32 leaves out, fence them, THEN cast to the leaves'
+        # dtypes: left free, the TPU compiler sinks the casts into the
+        # return all_to_all's relayout, whose codegen then takes minutes at
+        # model width (ROADMAP A9).  Values are unchanged.
+        f32 = packing.pack_spec(jax.tree_util.tree_map(
+            lambda g: jax.ShapeDtypeStruct(g.shape, jnp.float32), grads),
+            batch_ndim=0).unpack(vec, batch_ndim=0)
+        return jax.tree_util.tree_map(lambda o, g: o.astype(g.dtype),
+                                      jax.lax.optimization_barrier(f32),
+                                      grads)
     if wire_fmt.quantized:
         # Quantized coordinates through the first all_to_all (the comm
         # volume win): encode the full local message (block stats over the

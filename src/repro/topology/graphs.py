@@ -97,7 +97,11 @@ class Topology:
         larger gap = faster consensus.  A disconnected graph reports 0."""
         lam = np.linalg.eigvalsh(self.mixing)
         mags = np.sort(np.abs(lam))
-        return float(1.0 - mags[-2]) if self.num_nodes > 1 else 1.0
+        if self.num_nodes == 1:
+            return 1.0
+        # Clamped: a repeated eigenvalue 1 (disconnected graph) comes back
+        # from eigvalsh a few ulps above 1.
+        return float(np.clip(1.0 - mags[-2], 0.0, 1.0))
 
     def describe(self) -> dict:
         """The spectral-gap report (demo / benchmark / log line)."""

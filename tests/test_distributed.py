@@ -2,7 +2,7 @@
 
 Each test runs an inline program in a subprocess via tests/mesh_harness.py
 (8 forced host devices); programs use repro.compat for every mesh/shard_map
-touch so they run on jax 0.4.x through 0.7.x."""
+touch, the repo's one gate onto jax's moving mesh names."""
 import pytest
 
 from mesh_harness import run_py
@@ -535,6 +535,41 @@ def test_sharded_krum_selection_index_regression():
     assert "KRUM_SELECTS_2" in out
 
 
+def test_train_cli_places_state_in_the_steps_layout(tmp_path):
+    """launch/train.py lays the state out by the step's state_specs, so the
+    first step already sees the layout every later step sees (the node
+    axis sharded over the worker axes), instead of a full replica per
+    device that costs each device the whole federation's state and the
+    first step a program of its own."""
+    out = run_py("""
+        import jax
+        from repro.launch import steps as steps_lib, train
+        compile_step = steps_lib.compile_train_step
+        def spy(fn, **kw):
+            jstep = compile_step(fn, **kw)
+            def step(state, *args):
+                leaves = jax.tree_util.tree_leaves(state)
+                before = [(x.sharding, x.ndim) for x in leaves]
+                new_state, metrics = jstep(state, *args)
+                after = [x.sharding for x in jax.tree_util.tree_leaves(new_state)]
+                print("SAME_LAYOUT", all(a.is_equivalent_to(s, n) for (s, n), a
+                                         in zip(before, after)),
+                      "REPLICATED", sum(s.is_fully_replicated for s, _ in before))
+                return new_state, metrics
+            return step
+        steps_lib.compile_train_step = spy
+        train.main(["--arch", "mamba2-130m", "--reduced", "--steps", "2",
+                    "--seq", "16", "--mesh", "4x1", "--topology", "ring",
+                    "--aggregator", "trimmed_mean", "--comm", "sharded",
+                    "--attack", "sign_flip", "--byzantine", "1", "--vr", "saga"])
+    """, devices=4, timeout=300,
+        extra_env={"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    lines = [l for l in out.splitlines() if l.startswith("SAME_LAYOUT")]
+    # Two steps; only the replicated step counter (and no node-axis leaf)
+    # is fully replicated.
+    assert lines == ["SAME_LAYOUT True REPLICATED 1"] * 2, out
+
+
 def test_train_step_runs_on_mesh_and_attack_is_neutralized():
     """Train on a FIXED batch so the learning signal is deterministic: with
     sign_flip magnitude -3 and W=4/B=1 the mean aggregate is exactly zero
@@ -870,7 +905,6 @@ def test_require_distributed_and_comm_validation():
         from repro.launch import mesh as mesh_lib, steps as steps_lib
         from repro.models.api import build_model
 
-        assert compat.HAS_SHARD_MAP
         compat.require_distributed(min_devices=8)
         try:
             compat.require_distributed(min_devices=10**6)

@@ -33,6 +33,31 @@ def test_ssd_chunked_matches_recurrence():
         np.testing.assert_allclose(np.asarray(h), np.asarray(hr), rtol=1e-3, atol=1e-4)
 
 
+def test_ssd_chunked_gradient_finite_across_long_decay():
+    """A chunk spanning more decay than f32 exp can hold (here 64 tokens x
+    2 nats) keeps finite gradients; at mamba2-130m's published chunk of
+    256 tokens the step-0 gradient was NaN."""
+    B, L, H, P, N = 1, 64, 2, 4, 8
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (B, L, H, P))
+    b = jax.random.normal(ks[1], (B, L, N))
+    c = jax.random.normal(ks[2], (B, L, N))
+    dt = jnp.full((B, L, H), 2.0)
+    a = -jnp.ones((H,))
+
+    def loss(x, dt, a):
+        y, h = _ssd_chunked(x, b, c, dt, a, chunk=64)
+        return jnp.sum(y ** 2) + jnp.sum(h ** 2)
+
+    grads = jax.grad(loss, argnums=(0, 1, 2))(x, dt, a)
+    for g in grads:
+        assert bool(jnp.all(jnp.isfinite(g)))
+    yr, _ = ssd_reference(x, b, c, dt, a)
+    y, _ = _ssd_chunked(x, b, c, dt, a, chunk=64)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=1e-3,
+                               atol=1e-4)
+
+
 def _naive_attn(q, k, v, causal=True, window=None):
     b, s, h, d = q.shape
     kv = k.shape[2]

@@ -113,7 +113,7 @@ def test_checkpoint_missing_leaf_raises(tmp_path):
 def test_compat_layer_is_the_only_jax_version_gate():
     """Version-moving jax names must be touched only inside repro.compat
     (DESIGN.md Sec. 3): everything else goes through the compat surface so
-    the repo keeps running on jax 0.4.x through current."""
+    a future move of those names changes one module."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     banned = re.compile(
         r"jax\.shard_map|jax\.set_mesh|jax\.sharding\.AxisType"
