@@ -65,7 +65,7 @@ def test_kernel_check_on_a_four_device_mesh():
         err = chip_smoke.kernel_check(
             compat.make_mesh((4, 1), ("data", "model")), 4096)
         print("ERR", err)
-    """, devices=4, timeout=300)
+    """, devices=4, timeout=120)
     assert "on devices [0,1,2,3]" in out
     assert "ERR" in out
 

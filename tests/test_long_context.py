@@ -50,7 +50,7 @@ def test_sharded_decode_attention_matches_dense():
         np.testing.assert_allclose(np.asarray(out_ws), np.asarray(out_w),
                                    rtol=2e-4, atol=2e-4)
         print("SHARDED_DECODE_OK")
-    """)
+    """, timeout=120)
     assert "SHARDED_DECODE_OK" in out
 
 
@@ -82,5 +82,5 @@ def test_whisper_decode_matches_forward():
         np.testing.assert_allclose(np.asarray(logits_d), np.asarray(lf),
                                    rtol=2e-3, atol=2e-3)
         print("WHISPER_OK")
-    """, devices=1)
+    """, devices=1, timeout=120)
     assert "WHISPER_OK" in out

@@ -22,7 +22,7 @@ Three layers of contracts:
   the f32 trajectory.
 
 The distributed (shard_map) packed-vs-per-leaf pins live in
-``tests/test_distributed.py`` (they need the 8-device harness).
+``tests/test_distributed_packing.py`` (they need the 8-device harness).
 """
 import jax
 import jax.numpy as jnp

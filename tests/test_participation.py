@@ -3,8 +3,8 @@
 Hypothesis-driven (with the seeded ``tests/_hypothesis_fallback.py`` shim
 when the real package is absent) pins on the participation layer in
 isolation -- the cross-path step-level pins live in
-``tests/test_distributed.py`` and the convergence story in
-``tests/test_convergence.py``:
+``tests/test_distributed_participation.py`` and the convergence story
+in ``tests/test_convergence.py``:
 
 * Cohort sampling is a pure function of (seed, round): rebuilt plans agree
   element-wise, different seeds give different epoch shuffles, and

@@ -3,7 +3,7 @@
 Hypothesis-driven (with the seeded ``tests/_hypothesis_fallback.py`` shim
 when the real package is absent) pins on the wire layer in isolation --
 the cross-path step-level agreement pins live in
-``tests/test_distributed.py`` and the convergence floors in
+``tests/test_distributed_quantized.py`` and the convergence floors in
 ``tests/test_convergence.py``:
 
 * int8 round-trip error is bounded per coordinate by the per-block

@@ -318,6 +318,6 @@ def test_distributed_driver_rollback_is_deterministic(tmp_path):
         assert math.isfinite(r1["final_loss"]), r1
         assert r1 == r2, (r1, r2)
         print("RESILIENCE", json.dumps(r1))
-    """, devices=8, timeout=600)
+    """, devices=8, timeout=180)
     assert "rollback #1: restored step" in out
     assert "RESILIENCE" in out

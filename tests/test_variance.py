@@ -386,7 +386,7 @@ def test_every_reducer_trains_distributed_both_comm_modes():
                         assert elems == 2 * 4 * nparams, (elems, nparams)
                         assert float(m["vr_snapshot_rate"]) >= 0.0
                     print("VRCOV_OK", vr, comm, float(m["loss"]))
-    """, timeout=600)
+    """, timeout=480)
     for vr in VR_NAMES:
         for comm in ("gather", "sharded"):
             assert f"VRCOV_OK {vr} {comm}" in out, out
