@@ -17,9 +17,10 @@ per-device; the roofline collective term is bytes / link_bw.
 """
 from __future__ import annotations
 
+import math
 import re
 from collections import defaultdict
-from typing import Dict
+from typing import Dict, List
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -28,7 +29,10 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
-_OP_RE = re.compile(r"=\s*(?:\(|\S+\s+)?\s*([\w-]+)\(")
+_SRC_RE = re.compile(r'source_file="([^"]+)"')
+_FRAME_RE = re.compile(r"stack_frame_id=(\d+)")
+_ROW_RE = re.compile(r"^(\d+)\s+(.*)$")
+_FIELD_RE = re.compile(r"(\w+)=(\d+)")
 _IOTA_GROUPS_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _LIST_GROUPS_RE = re.compile(r"replica_groups=\{\{([\d,]+)\}")
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
@@ -56,9 +60,43 @@ def _group_size(line: str) -> int:
     return 2  # unknown: conservative smallest nontrivial group
 
 
-def collective_bytes(hlo_text: str) -> Dict[str, float]:
-    """Bytes moved per device, by collective kind + 'total'."""
-    out: Dict[str, float] = defaultdict(float)
+def _frame_files(hlo_text: str) -> Dict[int, str]:
+    """{stack_frame_id: file of that frame} from the module's
+    ``FileNames`` / ``FileLocations`` / ``StackFrames`` tables."""
+    tables: Dict[str, Dict[int, dict]] = {}
+    cur = None
+    for line in hlo_text.splitlines():
+        if line in ("FileNames", "FileLocations", "StackFrames"):
+            cur = tables.setdefault(line, {})
+            continue
+        m = _ROW_RE.match(line) if cur is not None else None
+        if not m:
+            cur = None
+            continue
+        fields = dict((k, int(v)) for k, v in _FIELD_RE.findall(m.group(2)))
+        cur[int(m.group(1))] = fields or {"name": m.group(2).strip('"')}
+    names = tables.get("FileNames", {})
+    locs = tables.get("FileLocations", {})
+    out = {}
+    for k, frame in tables.get("StackFrames", {}).items():
+        loc = locs.get(frame.get("file_location_id"), {})
+        out[k] = names.get(loc.get("file_name_id"), {}).get("name", "")
+    return out
+
+
+def collective_ops(hlo_text: str) -> List[dict]:
+    """One record per collective instruction (a ``-done`` half is skipped:
+    its payload is the matching ``-start``'s).
+
+    ``kind`` (one of the five above), ``result_bytes`` (the
+    result part only), ``elements`` (of the largest array in the result),
+    ``group`` (replica-group size) and ``source`` -- the file of the
+    innermost Python frame that traced it (``""`` without metadata): the
+    partitioner's collectives carry the frame of the op they serve.  The
+    frame tables are read as ``bench/lib/hlo.py``'s ``frame_files`` reads
+    them (the program does not import the benchmark): change both together."""
+    frames = _frame_files(hlo_text)
+    out: List[dict] = []
     for line in hlo_text.splitlines():
         ls = line.strip()
         if "=" not in ls:
@@ -69,16 +107,32 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
             continue
         op = m.group(1)
         kind = next((c for c in _COLLECTIVES if op.startswith(c)), None)
-        if kind is None:
+        if kind is None or op.endswith("-done"):
             continue
-        if op.endswith("-done"):   # payload counted at the matching -start
-            continue
-        result_seg = ls[eq + 1 : eq + m.start(1)]
-        result_b = _line_shapes(result_seg)
-        s = _group_size(ls)
+        shapes = _SHAPE_RE.findall(ls[eq + 1 : eq + m.start(1)])
+        result_b = sum(_shape_bytes(d, s) for d, s in shapes)
         if op.startswith(("all-gather-start", "all-reduce-start")):
             # tuple result (operand, result): halve to get the result part.
             result_b //= 2
+        src = _SRC_RE.search(ls)
+        frame = _FRAME_RE.search(ls)
+        out.append({
+            "kind": kind,
+            "result_bytes": result_b,
+            "elements": max((math.prod(int(d) for d in s.split(",") if d)
+                             for _, s in shapes), default=0),
+            "group": _group_size(ls),
+            "source": (src.group(1) if src else
+                       frames.get(int(frame.group(1)), "") if frame else ""),
+        })
+    return out
+
+
+def collective_bytes(hlo_text: str) -> Dict[str, float]:
+    """Bytes moved per device, by collective kind + 'total'."""
+    out: Dict[str, float] = defaultdict(float)
+    for c in collective_ops(hlo_text):
+        kind, result_b, s = c["kind"], c["result_bytes"], c["group"]
         if kind == "all-gather":
             moved = result_b * (s - 1) / s
         elif kind == "all-reduce":
@@ -93,10 +147,6 @@ def collective_bytes(hlo_text: str) -> Dict[str, float]:
         out["total"] += moved
         out[f"count:{kind}"] += 1
     return dict(out)
-
-
-def _line_shapes(segment: str) -> int:
-    return sum(_shape_bytes(d, s) for d, s in _SHAPE_RE.findall(segment))
 
 
 def collective_summary(hlo_text: str) -> str:

@@ -10,8 +10,9 @@ step of DESIGN.md Sec. 2:
      sharded like the gradients);
   3. Byzantine attack injection (mask-replace the first B workers);
   4. robust aggregation (every registry aggregator runs on both paths):
-       * ``comm="gather"``  -- paper-faithful replicated master (XLA
-         all-gathers the worker axes; the rule runs redundantly);
+       * ``comm="gather"``  -- paper-faithful master under the auto-sharded
+         jit (the packed full-vector rules run on the (W, D) buffer laid
+         out by coordinate, DESIGN.md Sec. 8);
        * ``comm="sharded"`` -- beyond-paper coordinate resharding (shard_map
          all_to_all; psum'd norms / partial Gram / per-block segments --
          DESIGN.md Sec. 2);
@@ -83,9 +84,10 @@ def _opt_structs_like(optimizer_name: str, ps: Pytree) -> Pytree:
 
 
 # The auto-jit gather master packs only the rules that need FULL-VECTOR
-# message geometry (and therefore replicate the (W, p) matrix anyway);
-# coordinate-separable and per-leaf rules stay leaf-sharded (see the
-# dispatch comment inside make_train_step).
+# message geometry (norms over the whole message, so one packed (W, D)
+# buffer laid out by coordinate serves them all); coordinate-separable and
+# per-leaf rules stay leaf-sharded (see the dispatch comment inside
+# make_train_step).
 PACKED_GATHER_RULES = frozenset(
     {"geomed", "geomed_groups", "krum", "centered_clip"})
 
@@ -223,7 +225,7 @@ def make_train_step(model: Model, robust: RobustConfig, train: TrainConfig,
         # the FIRST B slots, so the honest workers are the slots >= B).
         b = robust.num_byzantine if robust.attack != "none" else 0
         hmask = (jnp.arange(w) >= b).astype(jnp.float32)
-        var = telemetry.consensus_dist(msgs, hmask, max(w - b, 1))
+        honest_var = lambda m: telemetry.consensus_dist(m, hmask, max(w - b, 1))
 
         diag = None
         quarantined = None
@@ -233,17 +235,35 @@ def make_train_step(model: Model, robust: RobustConfig, train: TrainConfig,
                                    robust.aggregator in PACKED_GATHER_RULES))):
             # Flat-packed hot path (DESIGN.md Sec. 8): one (W, D) buffer
             # carries the messages through attack + aggregation.  The
-            # FULL-VECTOR rules route here by default -- they replicate the
-            # message matrix anyway (the Weiszfeld/Gram needs global
-            # norms), so packing collapses their per-leaf launches for
-            # free.  The VR state stays per-leaf so its tables/snapshots
-            # keep their model-axis sharding (DESIGN.md Sec. 4).  When
-            # staleness weights OR diagnostics are active EVERY gather rule
-            # routes here: both are flat-engine features (the per-leaf
-            # baseline predates them).
+            # FULL-VECTOR rules route here by default: packing collapses
+            # their per-leaf launches.  The VR state stays per-leaf so its
+            # tables/snapshots keep their model-axis sharding (DESIGN.md
+            # Sec. 4).  When staleness weights OR diagnostics are active
+            # EVERY gather rule routes here: both are flat-engine features
+            # (the per-leaf baseline predates them).
+            #
+            # Layout: the rows are pinned where the workers made them, then
+            # the buffer is resharded ONCE by coordinate -- each device holds
+            # all W rows of D/W coordinates.  Every reduction over W is then
+            # local, and the rules need only (W,)/(W, W) norm partials and
+            # scalar stopping statistics across devices; left row-sharded,
+            # XLA all-reduces a D-sized sum per Weiszfeld iteration.
             spec = robust.message_spec(msgs, batch_ndim=1)
+            by_coord = lambda x: jax.lax.with_sharding_constraint(
+                x, jax.sharding.NamedSharding(mesh, P(None, waxes)))
             buf = jax.lax.with_sharding_constraint(
                 spec.pack(msgs), jax.sharding.NamedSharding(mesh, P(waxes)))
+            # The quantized wire's round-trip slices the buffer at every
+            # leaf boundary; on coordinate shards the partitioner realigns
+            # each slice across devices (collective-permutes that grow with
+            # D: 6.6 against 1.5 GB per device a round for mamba2-130m on a
+            # v5e 2x2, compiled), so those formats reshard after it and pay
+            # one D-sized all-reduce for the attack's honest mean instead.
+            if not wire_fmt.quantized:
+                buf = by_coord(buf)
+            # On the packed wire values: by coordinate its sum over W is
+            # local; by rows it shares the attack's honest mean.
+            var = honest_var(buf)
             buf = attack_lib.apply_attack_stacked(
                 attack_cfg, buf, jax.random.fold_in(key, 2), spec=spec)
             if wire_fmt.quantized:
@@ -251,7 +271,7 @@ def make_train_step(model: Model, robust: RobustConfig, train: TrainConfig,
                 # rows are already a fixed point of the round-trip); the
                 # sharded branch gets the same treatment inside
                 # sharded_aggregate's encode.
-                buf = spec.wire_roundtrip(buf)
+                buf = by_coord(spec.wire_roundtrip(buf))
             flat_fn = robust.flat_aggregator_fn(spec)
             if robust.guards:
                 # Containment on the DEQUANTIZED wire (dequantize-then-
@@ -269,6 +289,10 @@ def make_train_step(model: Model, robust: RobustConfig, train: TrainConfig,
                 agg_vec, diag = out
             else:
                 agg_vec = out
+            # One all-gather of the f32 aggregate; the optimizer then runs
+            # in the parameters' own layout.
+            agg_vec = jax.lax.with_sharding_constraint(
+                agg_vec, jax.sharding.NamedSharding(mesh, P()))
             agg = spec.unpack(agg_vec, batch_ndim=0)
         else:
             # Everything else keeps per-leaf messages: comm="sharded" is
@@ -277,8 +301,9 @@ def make_train_step(model: Model, robust: RobustConfig, train: TrainConfig,
             # Sec. 2), and the coordinate-separable rules (mean/median/
             # trimmed_mean; geomed_blockwise is per-leaf by definition)
             # act shard-locally under the auto-sharded jit -- packing them
-            # into one replicated buffer would DESTROY their model-axis
-            # sharding for zero algorithmic gain.
+            # into one buffer sharded over the workers alone would DESTROY
+            # their model-axis sharding for zero algorithmic gain.
+            var = honest_var(msgs)
             msgs = attack_lib.apply_attack_stacked(
                 attack_cfg, msgs, jax.random.fold_in(key, 2))
             if robust.comm == "sharded":

@@ -334,3 +334,81 @@ def test_weighted_aggregation_sim_vs_gather_vs_sharded():
         print("WEIGHTED_AGREE")
     """, timeout=240)
     assert "WEIGHTED_AGREE" in out
+
+
+@pytest.mark.parametrize("wire", ["float32", "int8"])
+def test_gather_master_reshards_packed_buffer_by_coordinate(wire):
+    """Compiled layout of the packed gather master (one worker per device,
+    geomed under sign_flip): the (W, D) buffer crosses the devices by ONE
+    all-to-all into coordinate shards, the f32 aggregate leaves by ONE
+    all-gather, and the rule all-reduces or all-gathers nothing of D/W or
+    more elements -- the row-sharded layout all-reduced a D-sized sum per
+    Weiszfeld iteration.  An unquantized wire reshards before the attack,
+    so neither the attack nor the honest-variance metric moves D/W
+    elements either.  A quantized wire reshards after its round-trip, whose
+    per-leaf slices would otherwise be realigned across devices: no
+    collective-permute, and the attack's honest mean is its one D-sized
+    all-reduce.  Compile only; nothing runs."""
+    out = run_py(f"        wire = {wire!r}\n" + """
+        import dataclasses, math
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro import compat
+        from repro.configs import get_config
+        from repro.configs.base import TrainConfig
+        from repro.core.robust_step import RobustConfig
+        from repro.launch import hlo_analysis, sharding as shard_lib
+        from repro.launch import mesh as mesh_lib, steps as steps_lib
+        from repro.models.api import build_model
+
+        cfg = dataclasses.replace(get_config("mamba2-130m").reduced(),
+                                  num_layers=1, d_model=64, vocab_size=128)
+        w = 4
+        mesh = mesh_lib.make_host_mesh((w, 1), ("data", "model"))
+        model = build_model(cfg, remat=False, q_chunk=16, kv_chunk=16,
+                            loss_chunk=16)
+        robust = RobustConfig(aggregator="geomed", vr="saga",
+                              attack="sign_flip", num_byzantine=1,
+                              comm="gather", weiszfeld_iters=16,
+                              message_dtype=wire)
+        fn, specs, structs = steps_lib.make_train_step(
+            model, robust, TrainConfig(optimizer="adamw", lr=1e-3), mesh,
+            saga_num_samples=2)
+        state = jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            structs(), shard_lib.named(mesh, specs))
+        rows = NamedSharding(mesh, P("data"))
+        tok = jax.ShapeDtypeStruct((w, 2, 16), jnp.int32, sharding=rows)
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32)
+        with compat.use_mesh(mesh):
+            txt = steps_lib.compile_train_step(fn).lower(
+                state, {"tokens": tok, "labels": tok}, key).compile().as_text()
+        d = sum(math.prod(s.shape) for s in
+                jax.tree_util.tree_leaves(model.param_structs()))
+        ops = hlo_analysis.collective_ops(txt)
+        big = [c for c in ops if c["elements"] >= d // w]
+        def src(c, *files):
+            return c["source"].endswith(tuple("/repro/" + f for f in files))
+        local = ("core/geomed.py",) + (
+            () if wire == "int8" else ("core/attacks.py",
+                                       "telemetry/metrics.py"))
+        bad = [c for c in big if c["kind"] in ("all-reduce", "all-gather")
+               and src(c, *local)]
+        assert not bad, bad
+        reshard = [c for c in big if c["kind"] == "all-to-all"]
+        assert len(reshard) == 1, big
+        if wire == "int8":
+            assert not [c for c in ops if c["kind"] == "collective-permute"
+                        and src(c, "core/packing.py")], ops
+            honest = [c for c in big if c["kind"] == "all-reduce"
+                      and src(c, "core/attacks.py")]
+            assert len(honest) == 1, big
+        else:
+            assert src(reshard[0], "launch/steps.py"), big
+        out = [c for c in big if c["kind"] == "all-gather"
+               and src(c, "launch/steps.py")]
+        assert len(out) == 1 and out[0]["elements"] == d, big
+        assert out[0]["result_bytes"] == 4 * d, out
+        print("COORD_LAYOUT_OK", wire)
+    """, devices=4, timeout=180)
+    assert f"COORD_LAYOUT_OK {wire}" in out
